@@ -1,6 +1,6 @@
 """Shared benchmark harness.
 
-CPU-scale methodology (this container has no TPU):
+CPU-scale methodology (nothing here is measured on a chip):
 
 * **Acceptance lengths (L)** are *measured* — they are hardware-independent
   (they depend only on the token streams and the verifier's logits).

@@ -73,6 +73,9 @@ def main() -> None:
                     help="reduced sweeps (CI-friendly)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (
         ablation_bits,
         ablation_kv,

@@ -55,8 +55,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-from repro.kernels.pallas_compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 MASK_VAL = -1e30
 
@@ -85,7 +84,7 @@ def _flash_body(q_ref, k_ref, v_ref, qpos_ref, ks_ref, vs_ref, tm_ref, ws_ref,
     valid = kpos <= qpos                          # slot==position causality
     if tm_ref is not None:
         T = tm_ref.shape[-1]
-        ws = ws_ref[0]                            # scalar window start
+        ws = ws_ref[pl.program_id(0)]             # this row's window start
         rel = kpos - ws                           # (GT, bs) row-invariant
         in_win = (rel >= 0) & (rel < T)
         # ancestor gather as a matmul: onehot[j, c] = (slot_c - ws == j)
@@ -94,7 +93,8 @@ def _flash_body(q_ref, k_ref, v_ref, qpos_ref, ks_ref, vs_ref, tm_ref, ws_ref,
                       + s_idx * block_s - ws)).astype(jnp.float32)
         anc = jnp.dot(tm_ref[0, 0], onehot,
                       preferred_element_type=jnp.float32) > 0.5  # (GT, bs)
-        valid = jnp.where(in_win, anc, valid)
+        # boolean select as logic: Mosaic cannot lower a select of i1
+        valid = (in_win & anc) | (~in_win & valid)
     s = jnp.where(valid, s, MASK_VAL)
 
     m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
@@ -235,9 +235,9 @@ def flash_decode(
         tm = jnp.tile(tree_mask.astype(jnp.float32), (G, 1))   # (GT, T)
         in_specs.append(
             pl.BlockSpec((1, 1, GT, T), lambda b, h, s: (0, 0, 0, 0)))
-        in_specs.append(
-            pl.BlockSpec((1,), lambda b, h, s: (b,),
-                         memory_space=pltpu.SMEM))
+        # the whole (B,) vector sits in SMEM: a rank-1 block must span
+        # the array (or a multiple of 128), so no per-row (1,) block
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         operands += [tm[None, None], win_start.astype(jnp.int32)]
         kernel_fn = _kernel_tree_int8 if int8 else _kernel_tree
     else:
@@ -256,7 +256,7 @@ def flash_decode(
             pltpu.VMEM((GT, 1), jnp.float32),
             pltpu.VMEM((GT, dh), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -357,8 +357,7 @@ def flash_decode_paged(
         tm = jnp.tile(tree_mask.astype(jnp.float32), (G, 1))   # (GT, T)
         in_specs.append(pl.BlockSpec((1, 1, GT, T),
                                      lambda b, h, s, bt_ref: (0, 0, 0, 0)))
-        in_specs.append(pl.BlockSpec((1,), lambda b, h, s, bt_ref: (b,),
-                                     memory_space=pltpu.SMEM))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         operands += [tm[None, None], win_start.astype(jnp.int32)]
     kernel = functools.partial(_make_paged_kernel(int8, tree),
                                ns=nb, block_s=bs, scale=scale)
@@ -379,7 +378,7 @@ def flash_decode_paged(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, GT, dh), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -19,8 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-from repro.kernels.pallas_compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, dx_ref, dw_ref, o_ref, acc_ref, *, nk: int):
@@ -87,7 +86,7 @@ def int8_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

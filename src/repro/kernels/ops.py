@@ -2,7 +2,7 @@
 
 Dispatch policy (shared by :func:`w8a8_matmul` and :func:`flash_attend`):
   * on TPU backends the Pallas kernels run compiled;
-  * on CPU (this container) the default is the XLA path, which is
+  * on CPU the default is the XLA path, which is
     numerically identical (same int8 quantize semantics, exact int32 GEMM via
     ``dot_general(..., preferred_element_type=int32)``; same mask/online-
     softmax semantics for attention) and keeps the quantized operand int8

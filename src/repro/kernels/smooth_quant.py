@@ -3,9 +3,11 @@ INT8 quantization (paper Eq. 9, "Online Activation Smoothing and Quantization").
 
 One HBM read of X (bf16) and one HBM write of X̂ (int8) + Δx (f32) — the
 naive XLA composition (multiply, rowmax, divide, round, cast) otherwise
-costs three round-trips.  Rows are tiled into VMEM blocks of ``block_m``;
-the full K dimension of a row block is kept resident so the row-max and the
-quantize happen in a single pass.
+costs three round-trips.  Rows are tiled into VMEM blocks of at most
+``block_m`` rows; the full K dimension of a row block is kept resident so
+the row-max and the quantize happen in a single pass.  The block height is
+cut from K so that a block fits the TPU's 16 MiB scoped-VMEM default at the
+widest K (12288 for a 7B FFN down-projection).
 """
 from __future__ import annotations
 
@@ -14,11 +16,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-from repro.kernels.pallas_compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 INT8_MAX = 127.0
 EPS = 1e-8
+# scoped-VMEM bytes one row block may take, with headroom under the 16 MiB
+# default for the compiler's own scratch
+VMEM_BUDGET = 12 * 2**20
+
+
+def _block_rows(M: int, K: int, itemsize: int, block_m: int) -> int:
+    """Rows per block: the double-buffered input and int8 output plus one
+    f32 temporary per element stay within ``VMEM_BUDGET``.  Multiples of
+    32 keep the int8 output on its native (32, 128) tile."""
+    per_row = K * (2 * itemsize + 2 + 4)
+    fit = max(32, VMEM_BUDGET // per_row // 32 * 32)
+    return min(block_m, fit, M)
 
 
 def _kernel(x_ref, s_ref, q_ref, dx_ref):
@@ -41,7 +54,7 @@ def smooth_quant(
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     M, K = x.shape
-    bm = min(block_m, M)
+    bm = _block_rows(M, K, x.dtype.itemsize, block_m)
     Mp = (-M) % bm + M
     if Mp != M:
         x = jnp.pad(x, ((0, Mp - M), (0, 0)))
@@ -61,7 +74,7 @@ def smooth_quant(
             jax.ShapeDtypeStruct((Mp, K), jnp.int8),
             jax.ShapeDtypeStruct((Mp, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -11,13 +11,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-gated ``jax.make_mesh``: older jax releases have no
-    ``jax.sharding.AxisType`` (and default to auto axes anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis auto-partitioned."""
     return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes)
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
 

@@ -1,9 +1,10 @@
-"""Serving launcher.
+"""Serving launcher: one process on one device.
 
-On a TPU slice this builds the production mesh, shards the (quantized)
-params and engine state with the same rules the dry-run validated, and
-runs the speculative serving loop.  On CPU (this container) pass
-``--reduced`` to demo the identical code path at smoke scale.
+On a TPU chip the Pallas kernels run compiled (``repro.kernels.ops``);
+``chip_smoke.py`` at the checkout root is the full-width check of this
+path on one chip.  No code path here uses more than one device.  On a
+CPU (``JAX_PLATFORMS=cpu``) pass ``--reduced`` to run the identical code
+path at smoke scale, with the kernels' jnp twins.
 
 Drafting and verification are registry plugins: ``--drafter`` /
 ``--verifier`` name any registered implementation, and the engine applies
@@ -16,6 +17,7 @@ alone serves quantized verification from a BF16 checkpoint.
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
@@ -25,6 +27,7 @@ from repro.configs import get_config
 from repro.core.config import SpecConfig
 from repro.core.protocols import available_drafters, available_verifiers
 from repro.data import task_prompts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serving.engine import LEGACY_MODES, SpecEngine
 from repro.train.checkpoint import load_checkpoint
@@ -129,6 +132,7 @@ def main():
                     help="write the metrics in Prometheus text exposition "
                          "format (ServerMetrics.expose_text)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     import dataclasses
 
@@ -175,9 +179,11 @@ def main():
         args.task, args.batch, args.prompt_len, cfg.vocab_size))
     from repro.kernels.ops import attn_backend
     attn_path = cfg.attn_impl if cfg.attn_impl != "auto" else attn_backend()
+    platform = jax.devices()[0].platform
     print(f"arch={cfg.name} verifier={engine.verifier.name} "
           f"drafter={engine.drafter.name} kv_cache={cfg.kv_cache_dtype} "
-          f"kv_layout={args.kv_layout} attn={attn_path}")
+          f"kv_layout={args.kv_layout} attn={attn_path} "
+          f"device={platform}:{jax.devices()[0].device_kind}")
 
     import json
 
@@ -268,6 +274,9 @@ def main():
         if summary["deadlines"]["with_deadline"]:
             print(f"deadline hit-rate: "
                   f"{summary['deadlines']['hit_rate']:.3f}")
+        if c["failed"] or rb:
+            sys.exit(f"serve: {c['failed']} request(s) failed, "
+                     f"guardrails fired: {sorted(rb) or 'none'}")
         return
     if args.kv_layout == "paged":
         # paged is a serving-path layout: route the batch through the
@@ -280,17 +289,21 @@ def main():
         t0 = time.perf_counter()
         out = engine.generate_requests(params, reqs, tracer=tracer)
         wall = time.perf_counter() - t0
+        failed = [i for i, r in enumerate(out) if r is None]
+        if failed:
+            sys.exit(f"serve: requests {failed} failed")
         new_tokens = sum(r.new_tokens for r in out)
         L = sum(r.accept_len for r in out) / len(out)
         steps = max(r.steps for r in out)
         print(f"generated {new_tokens} tokens in {wall:.2f}s "
-              f"({new_tokens / max(wall, 1e-9):.1f} tok/s CPU)")
+              f"({new_tokens / max(wall, 1e-9):.1f} tok/s {platform}, "
+              "compile included)")
         print(f"verify steps={steps}  mean acceptance length L={L:.3f}")
         dump_observability()
         return
     r = engine.generate(params, prompts, args.new_tokens)
     print(f"generated {r.new_tokens} tokens in {r.wall_s:.2f}s "
-          f"({r.tokens_per_s:.1f} tok/s CPU)")
+          f"({r.tokens_per_s:.1f} tok/s {platform}, compile included)")
     print(f"verify steps={r.steps}  mean acceptance length L={r.mean_accept_len:.3f}")
     dump_observability()
 

@@ -1,9 +1,7 @@
-"""Training launcher.
+"""Training launcher: one process on one device (no mesh is built).
 
-On a TPU slice this builds the production mesh, shards params/optimizer
-with the FSDP+TP rules the dry-run validated, and runs the training loop.
-On CPU pass ``--reduced`` to run the identical code path at smoke scale
-(single-device mesh).
+On a CPU (``JAX_PLATFORMS=cpu``) pass ``--reduced`` to run the identical
+code path at smoke scale.
 
   python -m repro.launch.train --arch smollm-135m --reduced --steps 100
 """
@@ -16,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.data import lm_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.train.checkpoint import save_checkpoint
 from repro.train.optimizer import AdamWConfig, adamw_init
@@ -34,6 +33,7 @@ def main():
                     help="scanned-layer layout (production; default for >8 layers)")
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -15,7 +15,9 @@ from repro.models import Model
 from repro.serving.engine import SpecEngine
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ENV = {**os.environ, "PYTHONPATH": os.path.join(ROOT, "src")}
+# the launchers turn on the persistent compile cache; tests stay off it
+ENV = {**os.environ, "PYTHONPATH": os.path.join(ROOT, "src"),
+       "JAX_ENABLE_COMPILATION_CACHE": "false"}
 
 
 @pytest.mark.parametrize("arch", ["whisper-small", "llama-3.2-vision-90b"])
@@ -45,6 +47,20 @@ def test_serve_cli_smoke():
         capture_output=True, text=True, timeout=600, env=ENV, cwd=ROOT)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "mean acceptance length" in out.stdout
+
+
+def test_serve_cli_fails_when_a_request_fails():
+    """A contained request failure still ends the CLI with a non-zero
+    status, so a broken serving path cannot pass for a working one."""
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--arch", "smollm-135m",
+         "--reduced", "--serve", "--requests", "2", "--arrival-rate", "1000",
+         "--batch", "2", "--prompt-len", "16", "--new-tokens", "4",
+         "--fault-plan", "submit@0"],
+        capture_output=True, text=True, timeout=600, env=ENV, cwd=ROOT)
+    assert out.returncode != 0
+    assert "1 request(s) failed" in out.stderr
+    assert "served 1/2" in out.stdout
 
 
 def test_train_cli_smoke():
