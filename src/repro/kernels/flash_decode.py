@@ -60,6 +60,15 @@ from jax.experimental.pallas import tpu as pltpu
 MASK_VAL = -1e30
 
 
+def kernel_name(paged: bool, int8: bool, tree: bool) -> str:
+    """The ``pallas_call`` name of a variant: ``flash_decode`` or
+    ``flash_decode_paged``, then ``_tree`` and ``_int8`` as they apply.
+    Only the paged variants contain ``flash_decode_paged``, so a trace
+    tells the two layouts apart by that substring."""
+    return ("flash_decode" + ("_paged" if paged else "")
+            + ("_tree" if tree else "") + ("_int8" if int8 else ""))
+
+
 def _flash_body(q_ref, k_ref, v_ref, qpos_ref, ks_ref, vs_ref, tm_ref, ws_ref,
                 o_ref, m_ref, l_ref, acc_ref,
                 *, ns: int, block_s: int, scale: float):
@@ -260,6 +269,7 @@ def flash_decode(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=kernel_name(False, int8, tree),
     )(*operands)
 
     # (B, Hkv, GT, dh) → (B, T, Hq, dh)
@@ -382,6 +392,7 @@ def flash_decode_paged(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=kernel_name(True, int8, tree),
     )(bt.astype(jnp.int32), *operands)
 
     # (B, Hkv, GT, dh) → (B, T, Hq, dh)

@@ -93,5 +93,6 @@ def int4_matmul(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="int4_matmul",
     )(x_int8, w_packed, dx[:, None], dw[None, :])
     return out[:M, :N]

@@ -90,5 +90,6 @@ def int8_matmul(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="int8_matmul",
     )(x_int8, w_int8, dx[:, None], dw[None, :])
     return out[:M, :N]

@@ -78,5 +78,6 @@ def smooth_quant(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
+        name="smooth_quant",
     )(x, smooth[None, :])
     return q[:M], dx[:M, 0]

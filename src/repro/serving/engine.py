@@ -304,6 +304,9 @@ class SpecEngine:
         #                                     rid's table already stored
         shared_rows: int = 0,               # ... covering this many prompt
         #                                     rows (< P; cold tail is chunked)
+        tracer=None,                        # spans of each admission job
+        trace_tid: int = 0,
+        trace_rid: Optional[int] = None,    # request id the spans carry
     ) -> dict:
         """Admit ``request`` into slot ``row`` of a live decode state.
 
@@ -330,6 +333,12 @@ class SpecEngine:
         maximum) so admission prefill compiles once per group; ``params``
         must already be prepared (``prepare_params``).  Returns a new
         state dict; the input is not mutated.
+
+        ``tracer`` gets one span per job, each carrying ``rid``:
+        ``prefill.row_init`` (row state and ``init_cache``),
+        ``prefill.forward`` (prefill or warm gather + chunk),
+        ``prefill.scatter`` (pool scatter and block-table patch) and
+        ``prefill.drafter``.
         """
         drafter = drafter if drafter is not None else self.drafter
         P = request.prompt.size
@@ -337,29 +346,34 @@ class SpecEngine:
         pmax = P if pmax is None else pmax
         if not P <= pmax <= buf:
             raise ValueError(f"pmax {pmax} outside [{P}, {buf}]")
-        prompt = jnp.asarray(pad_prompt(request.prompt, pmax))[None]  # (1,pmax)
+        tr = tracer if tracer is not None else NULL_TRACER
+        targs = {"tid": trace_tid, "rid": trace_rid}
+        with tr.span("prefill.row_init", **targs):
+            # (1, pmax)
+            prompt = jnp.asarray(pad_prompt(request.prompt, pmax))[None]
 
-        state = dict(state)
-        state["stats"] = dict(state["stats"])
-        row_tokens = jnp.zeros((buf,), jnp.int32).at[:pmax].set(prompt[0])
-        state["tokens"] = state["tokens"].at[row].set(row_tokens)
-        state["length"] = state["length"].at[row].set(P)
-        state["target"] = state["target"].at[row].set(
-            P + request.max_new_tokens)
-        state["key"] = prng.fill_row(state["key"], row, request.seed)
-        state["stats"]["commits"] = state["stats"]["commits"].at[row].set(0)
-        state["stats"]["row_steps"] = \
-            state["stats"]["row_steps"].at[row].set(0)
-        if "bad" in state["stats"]:
-            state["stats"]["bad"] = \
-                state["stats"]["bad"].at[row].set(False)
+            state = dict(state)
+            state["stats"] = dict(state["stats"])
+            row_tokens = jnp.zeros((buf,), jnp.int32).at[:pmax].set(prompt[0])
+            state["tokens"] = state["tokens"].at[row].set(row_tokens)
+            state["length"] = state["length"].at[row].set(P)
+            state["target"] = state["target"].at[row].set(
+                P + request.max_new_tokens)
+            state["key"] = prng.fill_row(state["key"], row, request.seed)
+            state["stats"]["commits"] = \
+                state["stats"]["commits"].at[row].set(0)
+            state["stats"]["row_steps"] = \
+                state["stats"]["row_steps"].at[row].set(0)
+            if "bad" in state["stats"]:
+                state["stats"]["bad"] = \
+                    state["stats"]["bad"].at[row].set(False)
 
-        # KV/SSM cache row: fresh init + single-row prefill, scattered in.
-        # The padded prefill writes junk K/V at positions [P-1, pmax-1),
-        # but verify windows cover every position gap-free before the
-        # causal frontier reads it — dead weight, never live state.
-        paged = "bt" in state["cache"]
-        row_cache = self.model.init_cache(1, buf)
+            # KV/SSM cache row: fresh init + single-row prefill, scattered in.
+            # The padded prefill writes junk K/V at positions [P-1, pmax-1),
+            # but verify windows cover every position gap-free before the
+            # causal frontier reads it — dead weight, never live state.
+            paged = "bt" in state["cache"]
+            row_cache = self.model.init_cache(1, buf)
         # Warm prefix (prefix cache hit): gather the shared rows out of
         # the pool into the contiguous row cache and run *chunked*
         # prefill over the cold tail only.  The gather is an exact copy
@@ -370,76 +384,86 @@ class SpecEngine:
         # diverge from the solo run) and only skips re-*storing* the
         # shared blocks below — the capacity win without the compute
         # win.
-        use_chunk = (paged and shared_rows > 0
-                     and self.model.cfg.kv_cache_dtype != "int8")
-        if use_chunk:
-            c = int(shared_rows)
-            shared_ids = pool.owned(rid)[: int(shared_blocks)]
-            idx = jnp.asarray(np.asarray(shared_ids, np.int32))
-            warm = []
-            for pool_l, row_l in zip(state["cache"]["layers"],
-                                     row_cache["layers"]):
-                lay = dict(row_l)
-                for name, buf_l in pool_l.items():
-                    g = jnp.take(buf_l, idx, axis=0)
-                    g = g.reshape((-1,) + g.shape[2:])[:c]
-                    lay[name] = row_l[name].at[0, :c].set(
-                        g.astype(row_l[name].dtype))
-                warm.append(lay)
-            row_cache = dict(row_cache)
-            row_cache["layers"] = warm
-            if P - 1 > c:
-                row_cache = self.model.prefill_chunk(
-                    params, row_cache, prompt[:, c: P - 1], c)
-        else:
-            row_cache = self.model.prefill(
-                params, row_cache, prompt[:, :-1], aux_embeds=aux_embeds)
-        if paged:                        # paged: blocks instead of a row
-            if pool is None or rid is None:
-                raise ValueError("paged admission needs pool= and rid=")
-            n_shared = int(shared_blocks)
-            fork = n_shared > 0 and int(shared_rows) % pool.block_size != 0
-            if fork:
-                # the last shared block is a partially-matched boundary:
-                # fork it copy-on-write so our tail rows never touch the
-                # donor's copy.  The "copy" is free — the scatter below
-                # rewrites the fork block wholesale (gathered shared rows
-                # + computed tail + zero pad).
-                old = pool.owned(rid)[n_shared - 1]
-                new = pool.cow(rid, old)
-                if new == old and pool.prefix is not None:
-                    # sole owner (resurrected cached block): write in
-                    # place, but the donor's boundary entry may claim
-                    # rows beyond what we matched — drop it before we
-                    # overwrite them
-                    pool.prefix.evict_block(old)
-            pool.alloc(rid, pool.blocks_for(P) - n_shared)
-            ids = pool.owned(rid)
-            w0 = n_shared - (1 if fork else 0)   # first block we must write
-            bt = state["cache"]["bt"].at[row].set(SCRATCH_BLOCK)
-            bt = bt.at[row, : len(ids)].set(jnp.asarray(ids, jnp.int32))
-            cache = dict(state["cache"])
-            cache["layers"] = [
-                scatter_prefill_rows(pool_l, ids[w0:], row_l,
-                                     pool.block_size, first_block=w0)
-                for pool_l, row_l in zip(cache["layers"],
-                                         row_cache["layers"])]
-            cache["bt"] = bt
-            state["cache"] = cache
-        else:
-            state["cache"] = jax.tree.map(
-                lambda full, one: full.at[row].set(one[0]),
-                state["cache"], row_cache)
+        use_chunk = paged and self.chunked_admission(shared_rows)
+        with tr.span("prefill.forward", **targs):
+            if use_chunk:
+                c = int(shared_rows)
+                shared_ids = pool.owned(rid)[: int(shared_blocks)]
+                idx = jnp.asarray(np.asarray(shared_ids, np.int32))
+                warm = []
+                for pool_l, row_l in zip(state["cache"]["layers"],
+                                         row_cache["layers"]):
+                    lay = dict(row_l)
+                    for name, buf_l in pool_l.items():
+                        g = jnp.take(buf_l, idx, axis=0)
+                        g = g.reshape((-1,) + g.shape[2:])[:c]
+                        lay[name] = row_l[name].at[0, :c].set(
+                            g.astype(row_l[name].dtype))
+                    warm.append(lay)
+                row_cache = dict(row_cache)
+                row_cache["layers"] = warm
+                if P - 1 > c:
+                    row_cache = self.model.prefill_chunk(
+                        params, row_cache, prompt[:, c: P - 1], c)
+            else:
+                row_cache = self.model.prefill(
+                    params, row_cache, prompt[:, :-1], aux_embeds=aux_embeds)
+        with tr.span("prefill.scatter", **targs):
+            if paged:                        # paged: blocks instead of a row
+                if pool is None or rid is None:
+                    raise ValueError("paged admission needs pool= and rid=")
+                n_shared = int(shared_blocks)
+                fork = n_shared > 0 and int(shared_rows) % pool.block_size != 0
+                if fork:
+                    # the last shared block is a partially-matched boundary:
+                    # fork it copy-on-write so our tail rows never touch the
+                    # donor's copy.  The "copy" is free — the scatter below
+                    # rewrites the fork block wholesale (gathered shared rows
+                    # + computed tail + zero pad).
+                    old = pool.owned(rid)[n_shared - 1]
+                    new = pool.cow(rid, old)
+                    if new == old and pool.prefix is not None:
+                        # sole owner (resurrected cached block): write in
+                        # place, but the donor's boundary entry may claim
+                        # rows beyond what we matched — drop it before we
+                        # overwrite them
+                        pool.prefix.evict_block(old)
+                pool.alloc(rid, pool.blocks_for(P) - n_shared)
+                ids = pool.owned(rid)
+                w0 = n_shared - (1 if fork else 0)   # first block to write
+                bt = state["cache"]["bt"].at[row].set(SCRATCH_BLOCK)
+                bt = bt.at[row, : len(ids)].set(jnp.asarray(ids, jnp.int32))
+                cache = dict(state["cache"])
+                cache["layers"] = [
+                    scatter_prefill_rows(pool_l, ids[w0:], row_l,
+                                         pool.block_size, first_block=w0)
+                    for pool_l, row_l in zip(cache["layers"],
+                                             row_cache["layers"])]
+                cache["bt"] = bt
+                state["cache"] = cache
+            else:
+                state["cache"] = jax.tree.map(
+                    lambda full, one: full.at[row].set(one[0]),
+                    state["cache"], row_cache)
         # the drafter gets the UNPADDED prompt: draft-side caches may have
         # slots the drafter never rewrites (e.g. the pruned drafter skips
         # the last draft position on a full accept), so pad junk there
         # would be live — solo runs have zeros, and bit-identity demands
         # the admitted row does too
-        state["drafter_state"] = drafter.prefill_row(
-            self.model, params, state["drafter_state"], row,
-            jnp.asarray(request.prompt, jnp.int32)[None], buf,
-            aux_embeds=aux_embeds, draft_params=draft_params)
+        with tr.span("prefill.drafter", **targs):
+            state["drafter_state"] = drafter.prefill_row(
+                self.model, params, state["drafter_state"], row,
+                jnp.asarray(request.prompt, jnp.int32)[None], buf,
+                aux_embeds=aux_embeds, draft_params=draft_params)
         return state
+
+    def chunked_admission(self, shared_rows: int) -> bool:
+        """Whether a paged admission whose prompt shares ``shared_rows``
+        cached rows prefills only its cold tail (``prefill_chunk``)
+        instead of the whole padded prompt.  int8 KV keeps the full
+        recompute: attending a quantized prefix would diverge from the
+        solo run."""
+        return shared_rows > 0 and self.model.cfg.kv_cache_dtype != "int8"
 
     def _check_paged_supported(self):
         """Paged KV needs attention-family, full-causal, contiguous-slot
@@ -658,12 +682,29 @@ class SpecEngine:
                     "block_size": plan.block_size} if paged else {}),
             })
 
+            tkey = f"{drafter.name}:{self.verifier.name}"
+
+            def group_stats_cb(accepted, step_s, n_tokens, _k=tkey):
+                self.telemetry.on_decode_step(_k, accepted, step_s)
+
+            sched = Scheduler(batch, slots, policy=admission,
+                              tracer=tracer, trace_tid=gi,
+                              trace_ids=idxs,
+                              on_step_stats=group_stats_cb)
+
+            def _wait(st, _gi=gi, _sched=sched):
+                # step_s ends at the first sync: wait for the step here,
+                # inside the scheduler's decode span
+                with tr.span("step_wait", tid=_gi, step=_sched.steps):
+                    jax.block_until_ready(st["length"])
+                return st
+
             can_admit = release = preempt = None
             if paged:
                 for j, i in enumerate(idxs):
                     aux = (aux_embeds[i: i + 1]
                            if aux_embeds is not None else None)
-                    ctx.register(j, batch[j], aux_embeds=aux)
+                    ctx.register(j, batch[j], aux_embeds=aux, trace_id=i)
 
                 def admit(st, slot, j, _ctx=ctx, _drafter=drafter,
                           _pmax=pmax):
@@ -676,8 +717,9 @@ class SpecEngine:
                 if self.scfg.kv_preempt:
                     preempt = ctx.preempt
 
-                def step_fn(st, _s=step, _ctx=ctx):
-                    return _s(params, _ctx.prepare_step(st))
+                def step_fn(st, _s=step, _ctx=ctx, _sched=sched):
+                    st = _ctx.prepare_step(st, step=_sched.steps)
+                    return _wait(_s(params, st))
             else:
                 def admit(st, slot, j, _idxs=idxs, _drafter=drafter,
                           _pmax=pmax):
@@ -690,22 +732,13 @@ class SpecEngine:
                         draft_params=draft_params)
 
                 def step_fn(st, _s=step):
-                    return _s(params, st)
+                    return _wait(_s(params, st))
 
             group_on_tokens = None
             if on_tokens is not None:
                 def group_on_tokens(j, toks, _idxs=idxs):
                     on_tokens(_idxs[j], toks)     # group -> request index
 
-            tkey = f"{drafter.name}:{self.verifier.name}"
-
-            def group_stats_cb(accepted, step_s, n_tokens, _k=tkey):
-                self.telemetry.on_decode_step(_k, accepted, step_s)
-
-            sched = Scheduler(batch, slots, policy=admission,
-                              tracer=tracer, trace_tid=gi,
-                              trace_ids=idxs,
-                              on_step_stats=group_stats_cb)
             _, group_results = sched.run(
                 state, admit=admit, step=step_fn, t0=t_arrival,
                 can_admit=can_admit, release=release, preempt=preempt,
@@ -824,6 +857,7 @@ class PagedGroup:
         self.live: dict = {}       # slot -> (rid, demand_tokens)
         self.swap: dict = {}       # rid  -> host snapshot
         self._reqs: dict = {}      # rid  -> (request, aux_embeds)
+        self._trace_ids: dict = {}  # rid -> request id the spans carry
         self._tr = tracer if tracer is not None else NULL_TRACER
         self.trace_tid = int(trace_tid)
         # telemetry (benchmarks/ablation_kv.py shared-prefix section)
@@ -839,6 +873,7 @@ class PagedGroup:
         self.prefix_misses = 0     # sharing-eligible admissions, cold
         self.shared_tokens = 0     # prompt rows gathered from the cache
         self.cold_prefill_tokens = 0   # prompt rows prefilled cold
+        self.padded_prefill_tokens = 0  # rows prefilled past the prompt
         self.resurrections = 0     # cached-free blocks shared back in
         self.swap_out_bytes = 0    # host-snapshot traffic, out
         self.swap_in_bytes = 0     # ... and back in
@@ -847,9 +882,12 @@ class PagedGroup:
 
     # -- registration --------------------------------------------------
     def register(self, rid: int, request: GenerationRequest,
-                 aux_embeds=None) -> None:
-        """Associate ``rid`` with its request before any hook runs."""
+                 aux_embeds=None, *, trace_id: Optional[int] = None) -> None:
+        """Associate ``rid`` with its request before any hook runs.
+        ``trace_id`` is the id the scheduler's spans give the request
+        (default ``rid``); this group's spans carry it as ``rid``."""
         self._reqs[rid] = (request, aux_embeds)
+        self._trace_ids[rid] = rid if trace_id is None else int(trace_id)
 
     def demand_tokens(self, rid: int) -> int:
         r, _ = self._reqs[rid]
@@ -925,23 +963,34 @@ class PagedGroup:
                 self.prefix_hits += 1
             else:
                 self.prefix_misses += 1
+        cold = max(P - 1 - rows, 0)
+        # the full path prefills the prompt padded to pmax; the chunk
+        # path only its cold tail
+        padded = 0 if self.engine.chunked_admission(rows) else pmax - P
         self.shared_tokens += rows
-        self.cold_prefill_tokens += max(P - 1 - rows, 0)
+        self.cold_prefill_tokens += cold
+        self.padded_prefill_tokens += padded
         self.resurrections += n_res
         self.live[slot] = (rid, self.demand_tokens(rid))
         if ids and rows % bs != 0:
             self.cow_forks += 1          # prefill_into_slot forks below
-        with self._tr.span("prefill", tid=self.trace_tid, rid=rid,
-                           shared_rows=rows, cold_rows=max(P - 1 - rows, 0)):
+        trace_rid = self._trace_ids.get(rid, rid)
+        with self._tr.span("prefill", tid=self.trace_tid, rid=trace_rid,
+                           shared_rows=rows, cold_rows=cold,
+                           padded_rows=padded):
             state = self.engine.prefill_into_slot(
                 params, state, slot, r, pmax=pmax, drafter=drafter,
                 aux_embeds=aux, draft_params=draft_params,
                 pool=self.pool, rid=rid,
-                shared_blocks=len(ids), shared_rows=rows)
+                shared_blocks=len(ids), shared_rows=rows,
+                tracer=self._tr, trace_tid=self.trace_tid,
+                trace_rid=trace_rid)
         if self.index is not None and aux is None:
-            self.index.register(np.asarray(r.prompt).ravel(),
-                                self.pool.owned(rid),
-                                include_boundary=donate)
+            with self._tr.span("prefix.register", tid=self.trace_tid,
+                               rid=trace_rid):
+                self.index.register(np.asarray(r.prompt).ravel(),
+                                    self.pool.owned(rid),
+                                    include_boundary=donate)
         return state
 
     def release(self, state: dict, slot: int, rid: int) -> dict:
@@ -974,8 +1023,8 @@ class PagedGroup:
         L = int(np.asarray(state["length"])[slot])
         n_save = self.pool.blocks_for(max(L - 1, 0))
         ids = self.pool.owned(rid)[:n_save]
-        with self._tr.span("swap_out", tid=self.trace_tid, rid=rid,
-                           blocks=n_save):
+        with self._tr.span("swap_out", tid=self.trace_tid,
+                           rid=self._trace_ids.get(rid, rid), blocks=n_save):
             snap = {
                 "n_blocks": n_save,
                 "blocks": swap_out_blocks(state["cache"]["layers"], ids),
@@ -1005,7 +1054,8 @@ class PagedGroup:
 
     def _resume(self, state: dict, slot: int, rid: int) -> dict:
         """Re-admit a swapped request: fresh blocks, bit-exact copy-back."""
-        with self._tr.span("swap_in", tid=self.trace_tid, rid=rid):
+        with self._tr.span("swap_in", tid=self.trace_tid,
+                           rid=self._trace_ids.get(rid, rid)):
             return self._resume_inner(state, slot, rid)
 
     def _resume_inner(self, state: dict, slot: int, rid: int) -> dict:
@@ -1058,15 +1108,20 @@ class PagedGroup:
         return state
 
     # -- per-step maintenance ------------------------------------------
-    def prepare_step(self, state: dict) -> dict:
-        """Run before every decode step: block top-up + COW sweep."""
+    def prepare_step(self, state: dict, *, step: int = 0) -> dict:
+        """Run before every decode step: block top-up + COW sweep.
+        ``step`` is the scheduler's step count, for the trace."""
         with self._tr.span("append_blocks", tid=self.trace_tid):
             state = self.engine._append_paged_blocks(
                 state, self.pool, self.live, self.gamma)
         if self.index is None or not self.live:
             return state
-        # defensive copy-on-write: fork any still-shared block the next
-        # verify window would write (rows [L-1, L+gamma])
+        with self._tr.span("cow_sweep", tid=self.trace_tid, step=step):
+            return self._cow_sweep(state)
+
+    def _cow_sweep(self, state: dict) -> dict:
+        """Defensive copy-on-write: fork any still-shared block the next
+        verify window would write (rows [L-1, L+gamma])."""
         bt = state["cache"]["bt"]
         bt_host = np.asarray(bt)
         lengths = np.asarray(state["length"])
@@ -1104,6 +1159,7 @@ class PagedGroup:
             "shared_blocks": self.shared_blocks,
             "shared_tokens": self.shared_tokens,
             "cold_prefill_tokens": self.cold_prefill_tokens,
+            "padded_prefill_tokens": self.padded_prefill_tokens,
             "cow_forks": self.cow_forks,
             "resurrections": self.resurrections,
             "cached_evicted": self.pool.counters["cached_evicted"],

@@ -217,9 +217,9 @@ class AcceptanceStats:
 # ``PagedGroup.snapshot()`` emits except the non-additive pool gauges.
 _KV_SUMMED = (
     "prefix_hits", "prefix_misses", "shared_blocks", "shared_tokens",
-    "cold_prefill_tokens", "cow_forks", "resurrections", "cached_evicted",
-    "swap_out_blocks", "swap_in_blocks", "swap_out_bytes", "swap_in_bytes",
-    "preemptions",
+    "cold_prefill_tokens", "padded_prefill_tokens", "cow_forks",
+    "resurrections", "cached_evicted", "swap_out_blocks", "swap_in_blocks",
+    "swap_out_bytes", "swap_in_bytes", "preemptions",
 )
 
 

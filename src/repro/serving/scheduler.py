@@ -119,7 +119,8 @@ class Scheduler:
 
     * ``tracer`` — a :class:`repro.serving.trace.Tracer`.  The scheduler
       emits per-tick duration spans (``tick`` → ``admit`` / ``decode`` /
-      ``harvest`` / ``preempt`` on track ``trace_tid``) and per-request
+      ``readback`` / ``stream`` / ``harvest`` ⊃ ``release`` /
+      ``preempt`` on track ``trace_tid``) and per-request
       async lifecycle phases (``queued`` → ``running`` → finish, with
       ``preempted`` interludes and ``shed`` instants) keyed by the
       request's trace id.
@@ -131,8 +132,11 @@ class Scheduler:
       every decode step with the per-active-row committed-token counts
       (derived host-side from the length deltas the harvest already
       reads — no extra device sync), the step wall time, and their sum.
-      The serving loop folds this into acceptance histograms per
-      drafter×verifier.
+      ``step_s`` is the width of the ``decode`` span: ``step`` must end
+      at the first sync on the step's result (the serving lane's NaN
+      guard, the batch path's ``step_wait``), so that it holds the
+      device's step.  The serving loop folds this into acceptance
+      histograms per drafter×verifier.
     """
 
     requests: Sequence[GenerationRequest]
@@ -512,10 +516,26 @@ class Scheduler:
                                               on_fail=on_fail)
             return state, []
         step_s = clock() - t_step
+        step_i = self.steps
         self.steps += 1
 
-        lengths = np.asarray(state["length"])
-        targets = np.asarray(state["target"])
+        tr, tid = self._tr, self.trace_tid
+        with tr.span("readback", tid=tid, step=step_i):
+            lengths = np.asarray(state["length"])
+            targets = np.asarray(state["target"])
+            committed = {}             # slot -> tokens committed so far
+            for s in occupied:
+                P = self.requests[self._slots[s].request_index].prompt.size
+                committed[s] = int(min(lengths[s], targets[s])) - P
+            fresh = ([s for s in occupied
+                      if committed[s] > self._slots[s].streamed]
+                     if on_tokens is not None else [])
+            done = [s for s in occupied if lengths[s] >= targets[s]]
+            tokens_np = (np.asarray(state["tokens"]) if fresh or done
+                         else None)
+            if done:
+                commits = np.asarray(state["stats"]["commits"])
+                row_steps = np.asarray(state["stats"]["row_steps"])
         if occupied:
             accepted = []
             for s in occupied:
@@ -524,30 +544,20 @@ class Scheduler:
                 self._row_len[s] = cur
             if self.on_step_stats is not None:
                 self.on_step_stats(accepted, step_s, sum(accepted))
-        tokens_np = None                       # fetched lazily, once
-        if on_tokens is not None:
-            for s in occupied:
-                ev = self._slots[s]
-                P = self.requests[ev.request_index].prompt.size
-                committed = int(min(lengths[s], targets[s])) - P
-                if committed > ev.streamed:
-                    if tokens_np is None:
-                        tokens_np = np.asarray(state["tokens"])
+        if fresh:
+            with tr.span("stream", tid=tid, step=step_i, rows=len(fresh)):
+                for s in fresh:
+                    ev = self._slots[s]
+                    P = self.requests[ev.request_index].prompt.size
                     on_tokens(ev.request_index,
                               tokens_np[s, P + ev.streamed:
-                                        P + committed].copy())
-                    ev.streamed = committed
+                                        P + committed[s]].copy())
+                    ev.streamed = committed[s]
 
-        done = [s for s in occupied if lengths[s] >= targets[s]]
         harvested: List[int] = []
         if done:
             now = clock()
-            if tokens_np is None:
-                tokens_np = np.asarray(state["tokens"])
-            commits = np.asarray(state["stats"]["commits"])
-            row_steps = np.asarray(state["stats"]["row_steps"])
-            with self._tr.span("harvest", tid=self.trace_tid,
-                               rows=len(done)):
+            with tr.span("harvest", tid=tid, rows=len(done)):
                 for s in done:
                     ev = self._slots[s]
                     ev.harvest_step = self.steps
@@ -566,13 +576,16 @@ class Scheduler:
                     )
                     harvested.append(i)
                     self._first_admit_t.pop(i, None)
-                    self._tr.end_async("running", self._rid(i),
-                                       tokens=int(r.max_new_tokens),
-                                       steps=int(row_steps[s]))
+                    rid = self._rid(i)
+                    tr.end_async("running", rid,
+                                 tokens=int(r.max_new_tokens),
+                                 steps=int(row_steps[s]))
                     if self.on_event is not None:
                         self.on_event(ev)
                     if release is not None:
-                        state = release(state, s, i)
+                        with tr.span("release", tid=tid, step=step_i,
+                                     rid=rid):
+                            state = release(state, s, i)
                     self._slots[s] = None
         return state, harvested
 

@@ -283,7 +283,6 @@ class _Lane:
 
         def on_step_stats(accepted, step_s, n_tokens, _key=self.key):
             loop.metrics.on_decode_step(_key, accepted, step_s)
-            engine.telemetry.on_decode_step(_key, accepted, step_s)
             if self._collapse_hist is not None and accepted:
                 self._note_acceptance(sum(accepted) / len(accepted))
 
@@ -336,7 +335,7 @@ class _Lane:
     def on_submit(self, i: int, handle: StreamHandle) -> None:
         self.handles[i] = handle
         if self.ctx is not None:
-            self.ctx.register(i, handle.request)
+            self.ctx.register(i, handle.request, trace_id=handle.rid)
 
     def admit(self, state: dict, slot: int, i: int) -> dict:
         h = self.handles[i]
@@ -366,7 +365,7 @@ class _Lane:
                 # transient: poison only this step's local view
                 params = poison_params(self.params)
         if self.ctx is not None:
-            state = self.ctx.prepare_step(state)
+            state = self.ctx.prepare_step(state, step=self.sched.steps)
         pre = state                    # pure step: intact on any failure
         state = self.step(params, state)
         # fires inside the scheduler's "decode" span: a virtual-clock
@@ -405,8 +404,11 @@ class _Lane:
            requests** via :class:`RequestFault`, carrying the merged
            state so every other row's progress survives the tick.
         """
-        bad = np.asarray(state["stats"]["bad"])
         sched = self.sched
+        # the step's first sync: the decode span, and step_s, end here
+        with self.loop.tracer.span("step_wait", tid=self.tid,
+                                   step=sched.steps):
+            bad = np.asarray(state["stats"]["bad"])
         rows = [s for s in range(sched.batch_slots)
                 if bad[s] and sched._slots[s] is not None]
         if not rows:
@@ -680,6 +682,14 @@ class ServingLoop:
         Returns True if any lane did work."""
         if self.faults.fire("poll"):
             raise InjectedFault("injected poll failure (supervisor seam)")
+        if not self.busy:               # idle: no span per empty poll
+            return self._poll()
+        # one span over the loop's host work: routing, every lane's tick
+        # and the per-poll metrics and counters
+        with self.tracer.span("poll", step=self.total_steps):
+            return self._poll()
+
+    def _poll(self) -> bool:
         self._route_ingress()
         # client cancels land on a control queue (thread-safe); apply
         # them before admission so a cancelled queued request never

@@ -123,7 +123,9 @@ class _Span:
              **({"args": self._args} if self._args else {})})
         ann = self._tr._annotation
         if ann is not None:
-            self._device = ann(self._name)
+            # the arguments ride along as the profiler event's stats, so
+            # host spans join device executions by rid / step / rows
+            self._device = ann(self._name, **(self._args or {}))
             self._device.__enter__()
         return self
 
@@ -161,8 +163,11 @@ class Tracer:
         structurally valid.
     annotate_device:
         When True, each :meth:`span` additionally enters a
-        ``jax.profiler.TraceAnnotation`` so the span shows up in XLA
-        device profiles.  Lazily imported; silently off if unavailable.
+        ``jax.profiler.TraceAnnotation`` carrying the span's arguments,
+        so the span shows up, with them, in XLA device profiles on the
+        profiler's clock.  Lazily imported; silently off if unavailable.
+        Span arguments must be host values the caller already holds: a
+        device array there would add a sync to the hot path.
     """
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter, *,

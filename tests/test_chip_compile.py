@@ -6,13 +6,16 @@ shapes off the (8, 128) tiling, SMEM blocks that do not span their array,
 or more scoped VMEM than the chip allows.  These tests lower every kernel
 variant at the published widths of ``smollm-135m`` and ``quasar-paper-7b``
 for one chip of a ``v5e:2x2`` topology and check that a Mosaic kernel
-(``tpu_custom_call``) is in the compiled program.  Nothing runs.
+(``tpu_custom_call``) is in the compiled program, under the stable
+``name=`` its ``pallas_call`` gives it (the name a device trace shows).
+Nothing runs.
 
 The topology is described inside a module fixture, never at import time:
 only one process at a time may hold the TPU library, and every pytest
 worker imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +23,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.tree import TreeTemplate
-from repro.kernels.flash_decode import flash_decode, flash_decode_paged
+from repro.kernels.flash_decode import (flash_decode, flash_decode_paged,
+                                        kernel_name)
+from repro.kernels.int4_matmul import int4_matmul
 from repro.kernels.int8_matmul import int8_matmul
 from repro.kernels.smooth_quant import smooth_quant
 
@@ -43,9 +48,25 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, shapes, sharding) -> str:
+def _compile(fn, shapes, sharding) -> tuple:
+    """(lowered module text, compiled HLO text) of ``fn`` at ``shapes``."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    lowered = jax.jit(fn).lower(*args)
+    return lowered.as_text(), lowered.compile().as_text()
+
+
+def _assert_kernel(texts: tuple, name: str) -> None:
+    """The kernel is lowered under the ``name=`` its ``pallas_call``
+    gives, and the compiled program holds a Mosaic custom call named
+    exactly ``name``."""
+    lowered, hlo = texts
+    assert f'kernel_name = "{name}"' in lowered
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls, "no Mosaic kernel in the compiled program"
+    named = [line for line in calls
+             if re.search(rf"%{re.escape(name)}(\.\d+)? = ", line)]
+    assert named, (name, [c.split(" = ")[0] for c in calls])
 
 
 @pytest.mark.parametrize("M,K,N", [
@@ -58,7 +79,22 @@ def test_int8_matmul_compiles(one_chip, M, K, N):
     hlo = _compile(int8_matmul,
                    [((M, K), jnp.int8), ((K, N), jnp.int8),
                     ((M,), jnp.float32), ((N,), jnp.float32)], one_chip)
-    assert "tpu_custom_call" in hlo
+    _assert_kernel(hlo, "int8_matmul")
+
+
+@pytest.mark.parametrize("M,K,N", [
+    (VERIFY_M, 576, 1536),        # smollm-135m up-projection, verify
+    (PREFILL_M, 4096, 12288),     # quasar-paper-7b up-projection, prefill
+])
+def test_int4_matmul_lowers_named(one_chip, M, K, N):
+    """Lowered only: Mosaic cannot yet compile the kernel's int8 nibble
+    shifts for a v5e (``arith.shli``), and no serving path calls it."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in [((M, K), jnp.int8), ((K // 2, N), jnp.int8),
+                         ((M,), jnp.float32), ((N,), jnp.float32)]]
+    text = jax.jit(int4_matmul).lower(*args).as_text()
+    assert "tpu_custom_call" in text
+    assert 'kernel_name = "int4_matmul"' in text
 
 
 @pytest.mark.parametrize("M,K", [
@@ -70,7 +106,7 @@ def test_int8_matmul_compiles(one_chip, M, K, N):
 def test_smooth_quant_compiles(one_chip, M, K):
     hlo = _compile(smooth_quant, [((M, K), jnp.bfloat16),
                                   ((K,), jnp.float32)], one_chip)
-    assert "tpu_custom_call" in hlo
+    _assert_kernel(hlo, "smooth_quant")
 
 
 def _flash_shapes(paged, int8, tree, heads, B=4, S=512, bs=128):
@@ -121,4 +157,7 @@ def test_flash_decode_compiles(one_chip, paged, variant, arch):
     int8, tree = VARIANTS[variant]
     hlo = _compile(_flash_fn(paged, int8, tree),
                    _flash_shapes(paged, int8, tree, HEADS[arch]), one_chip)
-    assert "tpu_custom_call" in hlo
+    name = kernel_name(paged, int8, tree)
+    _assert_kernel(hlo, name)
+    # the trace reduction finds paged calls by this substring
+    assert ("flash_decode_paged" in name) == paged

@@ -20,6 +20,12 @@ Four layers:
    ``ServerMetrics.summary()`` carries populated ``acceptance`` and
    ``kv_cache`` sections with memory bounded in the request count; and
    generated tokens are bit-identical with tracing enabled vs disabled.
+5. **Shared clock** — a paged ``ServingLoop`` served under
+   ``jax.profiler`` with ``Tracer(annotate_device=True)``: every span
+   lands in the profile as a host event with its name and arguments, at
+   one fixed offset from the tracer's clock; admission and the decode
+   tick nest as documented; the padded-prefill counter matches the
+   prompts.
 """
 import math
 import os
@@ -352,12 +358,25 @@ def test_generation_bit_identical_tracing_on_vs_off(paged_engine):
     plain = engine.generate_requests(params, reqs)
     tracer = Tracer()
     traced = engine.generate_requests(params, reqs, tracer=tracer)
-    for a, b in zip(plain, traced):
+    annotated = engine.generate_requests(
+        params, reqs, tracer=Tracer(annotate_device=True))
+    for a, b, c in zip(plain, traced, annotated):
         np.testing.assert_array_equal(a.tokens, b.tokens)
+        np.testing.assert_array_equal(a.tokens, c.tokens)
         assert a.steps == b.steps and a.accept_len == b.accept_len
     assert validate(tracer.export()) == []
-    assert {"tick", "decode", "prefill", "queued", "running"} <= {
+    assert {"tick", "decode", "prefill", "queued", "running",
+            "step_wait", "readback", "release", *_ADMIT_CHILDREN} <= {
         e["name"] for e in tracer.events}
+    # the batch path's step_s ends at its step_wait sync, so it holds
+    # the step_wait span's width
+    spans = _closed(tracer.events)
+    waits = [s for s in spans if s["name"] == "step_wait"]
+    decodes = [s for s in spans if s["name"] == "decode"]
+    assert len(waits) == len(decodes) > 0
+    for w, d in zip(waits, decodes):
+        assert d["t0"] <= w["t0"] <= w["t1"] <= d["t1"]
+        assert w["args"]["step"] == d["args"]["step"]
     # batch-path telemetry accumulated on the engine itself
     assert engine.telemetry.mean_accept("ngram:bf16") is not None
 
@@ -403,3 +422,177 @@ def test_summary_is_json_serialisable():
     out = json.loads(json.dumps(m.summary()))
     assert out["counters"]["shed"] == 1
     assert out["acceptance"]["ngram:w8a8"]["steps"] == 1
+
+
+# ---------------------------------------------------------------------------
+# Shared clock: tracer spans on the profiler's trace
+# ---------------------------------------------------------------------------
+
+_ADMIT_CHILDREN = ("prefill.row_init", "prefill.forward", "prefill.scatter",
+                   "prefill.drafter")
+_PMAX = 16
+
+
+def _closed(events):
+    """Tracer B/E pairs as dicts with name, args, t0, t1 (seconds)."""
+    stacks, out = {}, []
+    for e in events:
+        key = (e.get("pid"), e.get("tid", 0))
+        if e["ph"] == "B":
+            stacks.setdefault(key, []).append(e)
+        elif e["ph"] == "E":
+            b = stacks[key].pop()
+            out.append({"name": b["name"], "args": b.get("args", {}),
+                        "tid": key[1], "t0": b["ts"] * 1e-6,
+                        "t1": e["ts"] * 1e-6})
+    return sorted(out, key=lambda s: (s["t0"], -s["t1"]))
+
+
+def _serve(engine, params, tracer=None):
+    """Four requests of distinct lengths (and first tokens, so no prefix
+    hits) through a two-slot paged ServingLoop; (tokens, kv snapshot)."""
+    from repro.serving import ServerConfig
+    from repro.serving.server import ServingLoop
+    loop = ServingLoop(engine, params,
+                       ServerConfig(batch_slots=2, max_prompt_len=_PMAX,
+                                    max_new_tokens=6),
+                       tracer=tracer)
+    vocab = engine.model.cfg.vocab_size
+    rng = np.random.default_rng(11)
+    handles = []
+    for i, P in enumerate((5, 9, 12, 16)):
+        prompt = rng.integers(0, vocab, P).astype(np.int32)
+        prompt[0] = 10 + i
+        handles.append(loop.submit(
+            GenerationRequest(prompt, max_new_tokens=6, seed=i)))
+    loop.drain()
+    kv = loop.metrics.kv_cache_summary()
+    return [h.result(timeout=0).tokens for h in handles], kv
+
+
+def _profile_events(path):
+    """(name, start s, stats) of every host event in a profile."""
+    import warnings
+
+    from jax.profiler import ProfileData
+    out = []
+    with warnings.catch_warnings():
+        # the profile's stat type warns on every access in this jax
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(str(path)).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    out.append((e.name, e.start_ns * 1e-9, dict(e.stats)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def profiled_serve(paged_engine, tmp_path_factory):
+    engine, params = paged_engine
+    plain, kv_off = _serve(engine, params)
+    _serve(engine, params, Tracer())          # compile every shape first
+    tracer = Tracer(annotate_device=True)
+    logdir = tmp_path_factory.mktemp("profile")
+    jax.profiler.start_trace(str(logdir))
+    try:
+        traced, kv_on = _serve(engine, params, tracer)
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = logdir.rglob("*.xplane.pb")
+    return plain, kv_off, traced, kv_on, tracer, _profile_events(pb)
+
+
+def test_spans_share_the_profilers_clock(profiled_serve):
+    """Every tracer span is a host event of the profile with the same
+    name and arguments; tracer time minus profile time is one offset."""
+    *_, tracer, host = profiled_serve
+    spans = _closed(tracer.events)
+    by_name = {}
+    for name, t, stats in sorted(host, key=lambda h: h[1]):
+        by_name.setdefault(name, []).append((t, stats))
+    offsets = []
+    for name in sorted({s["name"] for s in spans}):
+        mine = [s for s in spans if s["name"] == name]
+        theirs = by_name.get(name, [])
+        assert len(theirs) == len(mine), name
+        for s, (t, stats) in zip(mine, theirs):
+            want = {k: int(v) if isinstance(v, bool) else v
+                    for k, v in s["args"].items()}
+            assert stats == want, (name, stats, want)
+            offsets.append(s["t0"] - t)
+    assert len(offsets) > 50
+    assert max(offsets) - min(offsets) < 1e-3
+
+
+def _inside(child, parent):
+    return (child["tid"] == parent["tid"]
+            and parent["t0"] <= child["t0"] <= child["t1"] <= parent["t1"])
+
+
+def test_admission_and_tick_spans_nest(profiled_serve):
+    *_, tracer, _ = profiled_serve
+    spans = _closed(tracer.events)
+    assert validate(tracer.export()) == []
+
+    def named(n):
+        return [s for s in spans if s["name"] == n]
+
+    def parent_of(child, names):
+        ps = [p for p in spans if p["name"] in names and p is not child
+              and _inside(child, p)]
+        assert ps, (child["name"], names)
+        return min(ps, key=lambda p: p["t1"] - p["t0"])
+
+    admits = named("admit")
+    assert len(admits) == 4
+    for pre in named("prefill"):
+        assert parent_of(pre, {"admit"})["args"]["rid"] == pre["args"]["rid"]
+    for name in _ADMIT_CHILDREN:
+        kids = named(name)
+        assert len(kids) == 4, name
+        for k in kids:
+            p = parent_of(k, {"prefill"})
+            assert p["args"]["rid"] == k["args"]["rid"]
+    for reg in named("prefix.register"):
+        assert parent_of(reg, {"admit", "prefill"})["name"] == "admit"
+    assert len(named("prefix.register")) == 4
+    for name in ("cow_sweep", "step_wait"):
+        kids = named(name)
+        assert kids, name
+        for k in kids:
+            d = parent_of(k, {"decode"})
+            assert d["args"]["step"] == k["args"]["step"]
+    for name in ("readback", "stream", "release"):
+        kids = named(name)
+        assert kids, name
+        for k in kids:
+            assert parent_of(k, {"tick", "harvest", "decode"})["name"] in (
+                "tick", "harvest")
+    for t in named("tick"):
+        assert parent_of(t, {"poll"})["name"] == "poll"
+    # the decode span, and so step_s, ends after the step's first sync
+    for d in named("decode"):
+        waits = [w for w in named("step_wait") if _inside(w, d)]
+        assert len(waits) == 1
+
+
+def test_padded_prefill_rows_counted(profiled_serve):
+    plain, kv_off, traced, kv_on, tracer, _ = profiled_serve
+    want = sum(_PMAX - P for P in (5, 9, 12, 16))
+    assert kv_off["padded_prefill_tokens"] == want
+    assert kv_on["padded_prefill_tokens"] == want
+    assert kv_on["cold_prefill_tokens"] == sum(P - 1 for P in (5, 9, 12, 16))
+    pre = [s for s in _closed(tracer.events) if s["name"] == "prefill"]
+    assert sum(s["args"]["padded_rows"] for s in pre) == want
+    m = ServerMetrics()
+    m.add_kv_source("lane0", lambda: {"padded_prefill_tokens": want})
+    assert (f'serve_kv_cache_total{{event="padded_prefill_tokens"}} {want}'
+            in m.expose_text().splitlines())
+
+
+def test_tokens_identical_with_annotated_tracing(profiled_serve):
+    plain, _, traced, *_ = profiled_serve
+    for a, b in zip(plain, traced):
+        np.testing.assert_array_equal(a, b)
