@@ -5,6 +5,7 @@ Interpret-mode tests cannot see what the Mosaic compiler refuses: block
 shapes off the (8, 128) tiling, SMEM blocks that do not span their array,
 or more scoped VMEM than the chip allows.  These tests lower every kernel
 variant at the published widths of ``smollm-135m`` and ``quasar-paper-7b``
+(and the W8A8 GEMMs of ``codeqwen1.5-7b-l8``, the benchmark's 7B cell)
 for one chip of a ``v5e:2x2`` topology and check that a Mosaic kernel
 (``tpu_custom_call``) is in the compiled program, under the stable
 ``name=`` its ``pallas_call`` gives it (the name a device trace shows).
@@ -20,6 +21,7 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from jax._src.tpu_custom_call import MemorySpace
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.tree import TreeTemplate
@@ -34,6 +36,7 @@ HEADS = {"smollm-135m": (9, 3, 64), "quasar-paper-7b": (32, 8, 128)}
 # verify M = B·(γ+1) = 4·6 rows; prefill M = one 256-token admission
 VERIFY_M, PREFILL_M = 24, 256
 TREE = TreeTemplate((3, 1, 1, 1))          # root-heavy, 13-node window
+HBM_COLOR = MemorySpace.HBM.color          # a Mosaic call's HBM operand
 
 
 @pytest.fixture(scope="module")
@@ -74,12 +77,26 @@ def _assert_kernel(texts: tuple, name: str) -> None:
     (PREFILL_M, 1536, 576),       # smollm-135m down-projection, prefill
     (VERIFY_M, 4096, 12288),      # quasar-paper-7b up-projection, verify
     (PREFILL_M, 12288, 4096),     # quasar-paper-7b down-projection, prefill
+    # codeqwen1.5-7b-l8 (the benchmark's 7B cell) at 32 slots x 6 rows
+    (192, 4096, 4096),            # q, o
+    (192, 4096, 512),             # k, v (4 KV heads)
+    (192, 4096, 13440),           # gate, up
+    (192, 13440, 4096),           # down: K = 13440, no 512-multiple
+    (192, 4096, 92416),           # int8 LM head: a ragged last N block
+    (1024, 13440, 4096),          # down, prefill: two M blocks
+    (1024, 4096, 92416),          # LM head, prefill
 ])
 def test_int8_matmul_compiles(one_chip, M, K, N):
     hlo = _compile(int8_matmul,
                    [((M, K), jnp.int8), ((K, N), jnp.int8),
                     ((M,), jnp.float32), ((N,), jnp.float32)], one_chip)
     _assert_kernel(hlo, "int8_matmul")
+    # the planned blocks divide K and never need an operand padded
+    assert not re.search(r"\bpad\(", hlo[1])
+    # the weight (operand 1) is pinned to HBM, so the kernel streams it
+    # itself and no copy ahead of the call moves it into VMEM
+    assert re.search(r'"input_memory_space_colors":\[\{"operand_index":"1",'
+                     rf'"color":"{HBM_COLOR}"', hlo[1])
 
 
 @pytest.mark.parametrize("M,K,N", [

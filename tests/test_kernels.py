@@ -5,9 +5,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st  # hypothesis optional
+from jax.experimental import pallas as pl
 
 from repro.kernels import ref
-from repro.kernels.int8_matmul import int8_matmul
+from repro.kernels.int8_matmul import (M_CAP, VMEM_BUDGET, int8_matmul,
+                                       plan_blocks, vmem_bytes)
 from repro.kernels.smooth_quant import smooth_quant
 from repro.kernels import ops as kops
 
@@ -35,6 +37,72 @@ def test_int8_matmul_matches_ref(m, k, n, seed):
                         block_m=32, block_n=32, block_k=64, interpret=True)
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-5)
+
+
+def _step_linears(D, F, Hq, Hkv, dh, L, V=None):
+    """(K, N) of every W8A8 GEMM of one decode step: q, k, v, o, gate,
+    up, down per layer, then the LM head where it is quantized."""
+    layer = [(D, Hq * dh), (D, Hkv * dh), (D, Hkv * dh), (Hq * dh, D),
+             (D, F), (D, F), (F, D)]
+    return layer * L + ([(D, V)] if V else [])
+
+
+# the benchmark's configurations at their published widths
+# (bench/configs/); smollm-135m ties its head to the bf16 embedding
+STEP_LINEARS = {
+    "codeqwen1.5-7b-l8": _step_linears(4096, 13440, 32, 4, 128, 8, 92416),
+    "smollm-135m": _step_linears(576, 1536, 9, 3, 64, 30),
+}
+# grid steps of one decode step's GEMMs at 32 slots x (gamma + 1) = 192
+# rows, as PERF.md states them (61472 and 5460 under the former fixed
+# 128 x 128 x 512 blocks)
+STEP_GRID_192 = {"codeqwen1.5-7b-l8": 873, "smollm-135m": 270}
+
+
+@pytest.mark.parametrize("M", [192, 64, 256, 1024])
+@pytest.mark.parametrize("arch", sorted(STEP_LINEARS))
+def test_int8_matmul_plan(arch, M):
+    """Planned blocks at verify (192) and prefill rows: K blocks divide K
+    (no operand is padded), one M block up to ``M_CAP`` rows, every block
+    on int8's (32, 128) tiling or whole, and within the VMEM budget."""
+    steps = 0
+    for K, N in STEP_LINEARS[arch]:
+        bm, bn, bk = plan_blocks(M, K, N)
+        assert bk == K or (K % bk == 0 and bk % 128 == 0), (K, bk)
+        assert bn == N or bn % 128 == 0, (N, bn)
+        if M <= M_CAP:
+            assert bm == M
+        else:
+            assert bm % 32 == 0 and bm <= M_CAP
+            assert pl.cdiv(M, bm) == pl.cdiv(M, M_CAP)
+        assert vmem_bytes(bm, bk, bn) <= VMEM_BUDGET, (M, K, N)
+        steps += pl.cdiv(M, bm) * pl.cdiv(N, bn) * (K // bk)
+    if M == 192:
+        assert steps == STEP_GRID_192[arch]
+
+
+@pytest.mark.parametrize("M,K,N", [
+    (1000, 576, 1000),   # K with no 128-multiple divisor; M and N edges
+    (40, 4000, 1700),    # whole N over the budget: a ragged last N block
+    (192, 1536, 576),    # smollm-135m down-projection at verify rows
+])
+def test_int8_matmul_planned_blocks_exact(M, K, N):
+    """The planned blocks, with no override, give the reference's f32
+    output bit for bit, edge blocks included."""
+    bm, bn, bk = plan_blocks(M, K, N)
+    if (M, K, N) == (1000, 576, 1000):
+        assert bk == K and M % bm and N % bn
+    elif (M, K, N) == (40, 4000, 1700):
+        assert K % 128 and N % bn
+    k0, k1, k2, k3 = jax.random.split(jax.random.PRNGKey(M + K + N), 4)
+    xq = jax.random.randint(k0, (M, K), -127, 128, jnp.int8)
+    w_int8 = jax.random.randint(k1, (K, N), -127, 128, jnp.int8)
+    dx = jax.random.uniform(k2, (M,), jnp.float32, 1e-3, 2e-2)
+    w_scale = jax.random.uniform(k3, (N,), jnp.float32, 1e-3, 2e-2)
+    y = int8_matmul(xq, w_int8, dx, w_scale, out_dtype=jnp.float32,
+                    interpret=True)
+    y_ref = ref.int8_matmul_ref(xq, w_int8, dx, w_scale, jnp.float32)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_ref))
 
 
 @settings(max_examples=12, deadline=None)
