@@ -48,8 +48,7 @@ def compile_cell(cell, device) -> dict:
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
-    from bench.harness import program_config
-    from bench.weights import _build
+    from bench.harness import architecture, program_config
     from repro.core.config import SpecConfig
     from repro.core.paged_cache import init_paged_cache
     from repro.core.spec_engine import init_state
@@ -63,8 +62,9 @@ def compile_cell(cell, device) -> dict:
         verifier=s["verifier"], kv_layout="paged",
         kv_block_size=s["kv_block_size"]))
     z = lane_sizes(cell.mix, s)
-    raw = jax.eval_shape(lambda: _build(jax.random.PRNGKey(0),
-                                        cell.config["model"]))
+    build = architecture(cell.config).make_weights
+    raw = jax.eval_shape(lambda: build(cell.config["model"],
+                                       jax.random.PRNGKey(0)))
     prepared = jax.eval_shape(engine.prepare_params, raw)
 
     def state():

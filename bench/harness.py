@@ -2,11 +2,14 @@
 
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``.  Its files are
 found by name: the configuration at the path its ``configs`` entry names,
+the configuration's architecture module at
+``bench/architectures/<architecture>.py`` (``dense`` where the file names
+none) and its plain reference at ``bench/references/<reference>.py``,
 the traffic mix at ``bench/traffic/<traffic>.json``, the limits of its
 correctness check at ``bench/limits/<cell>.json`` and each per-layer
 metric's reader at ``bench/metrics/<metric>.py`` (a ``name.twin`` metric
-uses the reader of ``name``).  A later cell, mix or metric is new files
-and entries; nothing here names one.
+uses the reader of ``name``).  A later cell, mix, metric or architecture
+is new files and entries; nothing here names one.
 
 From the program the harness takes the system under test (``ServingLoop``
 over a ``SpecEngine``), its spans and its counters.  It makes the
@@ -33,15 +36,6 @@ from bench.traffic import Traffic, load_mix
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = Path(__file__).resolve().parent
 
-# configuration keys -> the program's ModelConfig fields
-_FIELDS = {
-    "hidden_size": "d_model", "num_hidden_layers": "num_layers",
-    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim", "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
-    "attention_bias": "attn_bias",
-}
 WARMUP_NEW_TOKENS = 1       # a warm-up request ends after one step
 SAMPLE_REQUESTS = 4         # finished requests the reference checks
 DRAIN_LIMIT_S = 120.0       # open loop: longest wait for due requests
@@ -87,16 +81,49 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
 # The system under test
 # ---------------------------------------------------------------------------
 
+def _bench_module(kind: str, name: str):
+    """``bench/<kind>/<name>.py``, loaded from its file."""
+    path = BENCH / kind / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def architecture(config: dict):
+    """The architecture module the configuration names (``dense`` where
+    it names none): its weights, its keys of ``ModelConfig`` and the
+    shapes the roofline readers count (``bench/architectures/dense.py``
+    lists what such a module provides)."""
+    return _bench_module("architectures",
+                         config.get("architecture", "dense"))
+
+
+def _as_registry(base, value):
+    """``value`` in the type of the registry's ``base`` value (as stated
+    where either is None)."""
+    return value if value is None or base is None else type(base)(value)
+
+
 def program_config(config: dict):
     """The program's ModelConfig for ``config``: the registry entry it
-    names, with every size the configuration file states.  The file is
-    the source: where the registry holds another size (its
-    ``registry_differs`` records which), the file's is served."""
+    names, with every size the configuration file states, through the
+    map of its architecture module.  The file is the source: where the
+    registry holds another size (its ``registry_differs`` records which),
+    the file's is served.  A key of the ``model`` block that the module
+    neither maps nor lists among its other keys is an error."""
     from repro.configs import get_config
+    arch = architecture(config)
     base = get_config(config["registry"])
     model = config["model"]
-    over = {field: type(getattr(base, field))(model[key])
-            for key, field in _FIELDS.items() if key in model}
+    unknown = set(model) - set(arch.program_fields) - set(arch.other_keys)
+    if unknown:
+        raise ValueError(f"{config['name']}: model keys {sorted(unknown)} "
+                         f"are neither program fields nor other keys of "
+                         f"its architecture")
+    over = {field: _as_registry(getattr(base, field), model[key])
+            for key, field in arch.program_fields.items() if key in model}
     if model.get("hidden_act", "silu") != base.act or not base.glu:
         raise ValueError(f"{config['name']}: activation differs")
     serving = config["serving"]
@@ -376,12 +403,7 @@ def sample_finished(driver: Driver, rids: List[int], seed: int,
 
 
 def reference_module(config: dict):
-    path = BENCH / "references" / f"{config['reference']}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_reference_{config['reference']}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _bench_module("references", config["reference"])
 
 
 def served_gaps(config: dict, weights, held: dict, traffic: Traffic) -> dict:
@@ -442,12 +464,4 @@ class MetricContext:
 def reader(metric: str) -> Callable:
     """``bench/metrics/<base>.py``'s ``read`` for ``metric``; a metric
     ``base.twin`` uses the reader of ``base``."""
-    base = metric.split(".")[0]
-    path = BENCH / "metrics" / f"{base}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{base}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
-
-
+    return _bench_module("metrics", metric.split(".")[0]).read

@@ -1,10 +1,11 @@
 """Kernels: the paged verify attention of the decode step against its
 roofline.  Each call's least time comes from the live positions each row
 attends (logged by the harness before every step), not the blocks the
-kernel walks; it is averaged over the traced steps and set against the
-device time of the calls the trace shows inside decode steps."""
-from bench.roofline import flash_decode_paged_cost, least_seconds, peaks
-from bench.weights import dims
+kernel walks, costed by the configuration's architecture module; it is
+averaged over the traced steps and set against the device time of the
+calls the trace shows inside decode steps."""
+from bench.harness import architecture
+from bench.roofline import least_seconds, peaks
 
 
 def read(ctx):
@@ -16,16 +17,14 @@ def read(ctx):
     steps = ctx.traced_steps()
     if n == 0 or busy <= 0 or not steps:
         return None
-    d = dims(ctx.cell.config["model"])
-    s = ctx.cell.config["serving"]
-    int8 = s["kv_cache_dtype"] == "int8"
+    config = ctx.cell.config
+    s = config["serving"]
+    cost = architecture(config).verify_attention_cost
     p = peaks(ctx.device_kind)
     bf16 = p["bf16_flops_per_s"]
     least = 0.0
     for rows in steps:
-        flops, nbytes = flash_decode_paged_cost(
-            s["gamma"] + 1, d["H"], d["Hkv"], d["dh"], rows,
-            1 if int8 else 2, int8)
+        flops, nbytes = cost(config["model"], s, s["gamma"] + 1, rows)
         least += least_seconds(flops, nbytes, bf16, p["hbm_bytes_per_s"])[0]
     per_call = least / len(steps)
     return 100.0 * per_call * n / busy

@@ -111,7 +111,7 @@ def served_gaps(params: dict, model: dict, prompt: np.ndarray,
     that chose ``served[j]``.  ``rows_to`` pads the rows read (one
     compiled shape per cell).  Returns (gaps, logit standard deviations)
     as float64 arrays of ``len(served)``."""
-    from bench.weights import dims
+    from bench.architectures.dense import dims
     d = dims(model)
     P, N = int(prompt.size), int(served.size)
     seq = np.zeros((pad_to,), np.int32)

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import json
 from pathlib import Path
-from typing import Iterable, Sequence
+from typing import Iterable
 
 PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
 
@@ -55,35 +55,3 @@ def least_seconds(ops: float, nbytes: float, peak_ops: float,
     """(least time, which bound) for work of ``ops`` and ``nbytes``."""
     t_ops, t_bytes = ops / peak_ops, nbytes / peak_bytes
     return (t_ops, "compute") if t_ops >= t_bytes else (t_bytes, "memory")
-
-
-def dense_linears(D: int, F: int, Hq: int, Hkv: int, dh: int) -> list:
-    """(K, N) of the seven linears of one dense block, in call order:
-    q, k, v, o, gate, up, down."""
-    return [(D, Hq * dh), (D, Hkv * dh), (D, Hkv * dh), (Hq * dh, D),
-            (D, F), (D, F), (F, D)]
-
-
-def step_int8_matmul_calls(d: dict, rows: int, quantized_head: bool
-                           ) -> list:
-    """(M, K, N) of every W8A8 GEMM in one decode step of ``rows`` rows."""
-    calls = [(rows, K, N) for _ in range(d["L"])
-             for K, N in dense_linears(d["D"], d["F"], d["H"], d["Hkv"],
-                                       d["dh"])]
-    if quantized_head:
-        calls.append((rows, d["D"], d["V"]))
-    return calls
-
-
-def model_flops_per_token(d: dict) -> int:
-    """2 x the parameters a token multiplies by: every block's linears and
-    the LM head (the embedding lookup is free)."""
-    per_layer = sum(K * N for K, N in dense_linears(
-        d["D"], d["F"], d["H"], d["Hkv"], d["dh"]))
-    return 2 * (d["L"] * per_layer + d["D"] * d["V"])
-
-
-def attention_flops(d: dict, context: Sequence[int]) -> int:
-    """Flops of attention for tokens at the given context lengths
-    (``Q K^T`` and ``P V``, every layer)."""
-    return sum(4 * d["L"] * d["H"] * d["dh"] * c for c in context)

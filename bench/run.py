@@ -46,8 +46,12 @@ def log(msg: str) -> None:
 
 
 class TracedSpan:
-    """Profiles one stretch of the window, from ``at`` seconds after it
-    opens for ``seconds``; call :meth:`tick` as the window runs."""
+    """Profiles one stretch of the window: from the first tick ``at``
+    seconds or more after it opens, for ``seconds`` from when the
+    profiler has started; call :meth:`tick` as the window runs.  Ticks
+    fall between the loop's polls, and one poll can admit requests for
+    seconds, so the stretch is timed from its own start: timed from
+    ``at``, a late first tick left a stretch one poll long."""
 
     def __init__(self, at: float, seconds: float):
         self.at, self.seconds = at, seconds
@@ -66,7 +70,8 @@ class TracedSpan:
             self._ann.__enter__()
             self.t0 = time.perf_counter()
             self.state = "tracing"
-        elif self.state == "tracing" and elapsed >= self.at + self.seconds:
+        elif (self.state == "tracing"
+              and time.perf_counter() - self.t0 >= self.seconds):
             self.stop()
 
     def stop(self) -> None:
@@ -111,7 +116,7 @@ def run_once(cell, seed: int, seconds: float, trace: bool, devices,
 
     counter = H.CompileCounter()
     model = cell.config["model"]
-    weights = make_weights(model, seed)
+    weights = make_weights(cell.config, seed)
     traffic = generate(cell.mix, seed, model["vocab_size"], seconds,
                        warmup_new_tokens=H.WARMUP_NEW_TOKENS)
     tracer = None

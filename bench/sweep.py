@@ -44,7 +44,7 @@ def main() -> int:
     enable_compile_cache()
     rates = [float(r) for r in args.rates.split(",")]
     model = cell.config["model"]
-    weights = make_weights(model, args.seed)
+    weights = make_weights(cell.config, args.seed)
     mixes = []
     for i, r in enumerate(rates):
         mix = json.loads(json.dumps(cell.mix))

@@ -120,15 +120,21 @@ def test_least_seconds_and_peaks():
 
 
 def test_step_calls_and_model_flops():
-    d = {"D": 4096, "F": 13440, "H": 32, "Hkv": 32, "dh": 128, "V": 92416,
-         "L": 8}
-    calls = RF.step_int8_matmul_calls(d, 96, True)
+    from bench.harness import architecture
+    dense = architecture({})
+    model = {"hidden_size": 4096, "intermediate_size": 13440,
+             "num_attention_heads": 32, "num_key_value_heads": 32,
+             "head_dim": 128, "vocab_size": 92416, "num_hidden_layers": 8}
+    calls = dense.int8_matmul_calls(model, 96)
     assert len(calls) == 8 * 7 + 1
     assert calls[-1] == (96, 4096, 92416) and calls[6] == (96, 13440, 4096)
+    assert len(dense.int8_matmul_calls(
+        dict(model, tie_word_embeddings=True), 96)) == 8 * 7
     per_layer = 4 * 4096 * 4096 + 3 * 4096 * 13440
-    assert RF.model_flops_per_token(d) == 2 * (8 * per_layer
-                                               + 4096 * 92416)
-    assert RF.attention_flops(d, [10, 20]) == 4 * 8 * 32 * 128 * 30
+    assert dense.flops_per_token(model) == 2 * (8 * per_layer
+                                                + 4096 * 92416)
+    assert dense.attention_flops_per_position(model) * 30 == \
+        4 * 8 * 32 * 128 * 30
 
 
 # -- trace reduction -----------------------------------------------------------

@@ -139,3 +139,33 @@ def test_run_py_refuses_without_a_tpu(tmp_path):
     assert p.returncode != 0
     assert p.stdout.strip() == ""
     assert "needs a TPU" in p.stderr
+
+
+@pytest.mark.parametrize("first_tick", [2.0, 9.0])
+def test_traced_span_lasts_its_seconds_after_a_late_first_tick(
+        monkeypatch, first_tick):
+    """The traced stretch runs ``seconds`` from when the profiler began,
+    even where the first tick past ``at`` came after ``at + seconds``
+    (one long poll of admissions): the stretch is then not one poll."""
+    import time
+
+    import jax
+
+    from bench.run import TracedSpan
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *a, **k: calls.append("start"))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append("stop"))
+    span = TracedSpan(at=1.0, seconds=0.2)
+    span.tick(0.5)
+    assert span.state == "waiting"
+    span.tick(first_tick)
+    assert span.state == "tracing"
+    span.tick(first_tick + 0.01)          # the next poll, moments later
+    assert span.state == "tracing"
+    time.sleep(0.25)
+    span.tick(first_tick + 0.3)
+    assert span.state == "done" and calls == ["start", "stop"]
+    assert span.t1 - span.t0 >= 0.2
+    span.cleanup()

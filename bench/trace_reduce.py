@@ -11,8 +11,9 @@ closes around it.  On the device plane (``/device:TPU:0``):
 * each program execution (line ``XLA Modules``) is the decode step when
   its module is the decode step's (``STEP_MODULE``), and otherwise one of
   the eager programs admission and the block-table patches dispatch;
-* a kernel's calls are the custom calls whose op name or metadata names
-  the kernel, inside decode-step executions;
+* each custom call inside a decode-step execution is a call of the kernel
+  the program named (``pallas_call(name=...)``, which becomes the HLO
+  instruction's name), counted under that name;
 * each idle gap is charged to the innermost host span that covers its
   middle, among the spans the program's tracer and the harness open.
 
@@ -29,7 +30,6 @@ from pathlib import Path
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 STEP_MODULE = "jit_counted"     # the serving lane's jitted decode step
-KERNELS = ("flash_decode_paged", "int8_matmul", "smooth_quant")
 TRACED_SPAN = "bench.traced"
 
 
@@ -87,23 +87,30 @@ def clip(evs: Sequence[Ev], lo: float, hi: float) -> List[Ev]:
 def _base(name: str) -> str:
     """A program's or an op's name without its instance number:
     ``jit_counted(7)`` -> ``jit_counted``; ``%fusion.12 = bf16[..] ...``
-    (an HLO instruction) -> ``fusion``."""
+    (an HLO instruction) -> ``fusion``; ``%flash_decode_paged_int8.3``
+    -> ``flash_decode_paged_int8``."""
     name = name.split(" = ", 1)[0].lstrip("%")
     name = re.sub(r"\(\d+\)$", "", name)
-    return re.sub(r"[.\d]+$", "", name) or name
+    return re.sub(r"(\.\d+)+$", "", name) or name
+
+
+_CUSTOM_CALL = re.compile(r"[\s)}\]]custom-call\(")
+_NAME_STACK_CALL = re.compile(r"(\w+)\)?/pallas_call")
 
 
 def kernel_of(e: Ev) -> Optional[str]:
     """The kernel a device op is a call of, or None.  A kernel call is a
-    custom call whose name or metadata names one of ``KERNELS``."""
-    text = " ".join([e.name, *e.stats.values()])
-    if "custom" not in text and "pallas" not in text and \
-            "mosaic" not in text.lower():
-        return None
-    for k in KERNELS:
-        if k in text:
-            return k
-    return None
+    custom call.  Its name is the HLO instruction's (``%int8_matmul.58 =
+    ... custom-call(...)``), which the kernel's ``pallas_call(name=...)``
+    sets; where the instruction is an unnamed ``custom-call``, the name
+    is the call's in the op's name stack (``.../int8_matmul/pallas_call``)
+    if its metadata has one."""
+    head, _, rhs = e.name.partition(" = ")
+    name = _base(head)
+    if name == "custom-call":
+        m = _NAME_STACK_CALL.search(" ".join(e.stats.values()))
+        return m.group(1) if m else name
+    return name if _CUSTOM_CALL.search(" " + rhs) else None
 
 
 @dataclasses.dataclass
@@ -117,10 +124,14 @@ class Reduced:
     gaps: List[Tuple[str, float]]                 # (host span, seconds)
 
     def kernel_calls(self, name: str) -> int:
-        return self.kernels.get(name, (0, 0.0))[0]
+        """Calls of the kernels whose name contains ``name``
+        (``flash_decode_paged`` holds ``flash_decode_paged_int8``)."""
+        return sum(n for k, (n, _) in self.kernels.items() if name in k)
 
     def kernel_s(self, name: str) -> float:
-        return self.kernels.get(name, (0, 0.0))[1]
+        """Device time of the kernels whose name contains ``name``."""
+        return sum((s for k, (_, s) in self.kernels.items() if name in k),
+                   0.0)
 
     def breakdown(self) -> dict:
         return {"device_ops": [[n, s] for n, s in self.ops[:10]],
